@@ -301,6 +301,8 @@ class CheckpointManager:
                 )
                 entry = manifest["index"][f"{name}/{key}"]
                 arr = np.load(os.path.join(d, entry["file"]))
+                if arr.dtype.kind == "V":  # .npy keeps bf16 as raw 2-byte records
+                    arr = arr.view(np.dtype(entry["dtype"]))
                 assert list(arr.shape) == list(leaf.shape), (key, arr.shape, leaf.shape)
                 leaves.append(arr.astype(leaf.dtype))
             tree = jax.tree_util.tree_unflatten(

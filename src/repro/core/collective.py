@@ -31,13 +31,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 
 def _axis_size(axis_names: Sequence[str]) -> int:
     m = 1
     for a in axis_names:
-        m *= compat.axis_size(a)
+        m *= lax.axis_size(a)
     return m
 
 

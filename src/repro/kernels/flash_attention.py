@@ -3,6 +3,12 @@
 TPU adaptation notes (vs the CUDA flash-attention algorithm):
 - Tiling is chosen for the MXU (128x128 systolic array) and VMEM residency:
   q/k blocks default to 128 rows, head_dim rides along in full (<= 128).
+- Heads sit OUTSIDE the last two dims of every block: Mosaic needs a
+  block's last two dims divisible by (8, 128) or equal to the array's, so
+  the kernels see (B, H, T, D) with batch and head squeezed and each block
+  is a (rows, D) tile. Per-row statistics (lse, delta) are (B, H, T, 1).
+- Matmuls take the inputs' dtype and accumulate in f32
+  (``preferred_element_type``); the softmax runs in f32.
 - The streaming softmax state (m, l, acc) lives in VMEM scratch and is
   carried across the *innermost grid dimension* (k blocks), declared
   "arbitrary" so Mosaic keeps it sequential; batch/head/q-block dims are
@@ -13,7 +19,7 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
   gradients) so the kernels stay MHA-shaped — one fewer index map level in
   VMEM addressing.
 
-Layouts: q, k, v, o are (B, T, H, D); lse is (B, H, T) fp32.
+Layouts: q, k, v, o are (B, H, T, D); lse is (B, H, T, 1) fp32.
 """
 from __future__ import annotations
 
@@ -26,6 +32,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_SQ = pl.squeezed
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scores(q, k, qi, ki, *, scale, causal, blk_q, blk_k):
+    s = _dot(q, k, _NT) * scale                                # (bq, bk)
+    if causal:
+        qpos = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kpos = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(qpos >= kpos, s, NEG_INF)
+    return s
+
+
+def _runs(qi, ki, *, causal, blk_q, blk_k):
+    """False only for k blocks strictly above the causal diagonal."""
+    return (ki * blk_k <= qi * blk_q + blk_q - 1) if causal else (ki >= 0)
 
 
 # ------------------------------------------------------------------ forward
@@ -42,73 +69,62 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    run = (ki * blk_k <= qi * blk_q + blk_q - 1) if causal else (ki >= 0)
-
-    @pl.when(run)
+    @pl.when(_runs(qi, ki, causal=causal, blk_q=blk_q, blk_k=blk_k))
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = q @ k.T                                            # (bq, bk)
-        if causal:
-            qpos = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        v = v_ref[...]
+        s = _scores(q_ref[...], k_ref[...], qi, ki, scale=scale,
+                    causal=causal, blk_q=blk_q, blk_k=blk_k)
+        m_prev = m_scr[...]                                    # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + p @ v
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _dot(p.astype(v.dtype), v)
         m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _done():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = m_scr[...] + jnp.log(l)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[...] + jnp.log(l)
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, scale=None,
                         blk_q=128, blk_k=128, interpret=False):
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    assert k.shape == (B, Tk, H, D) and v.shape == (B, Tk, H, D), "MHA-shaped"
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    assert k.shape == (B, H, Tk, D) and v.shape == (B, H, Tk, D), "MHA-shaped"
     blk_q = min(blk_q, Tq)
     blk_k = min(blk_k, Tk)
     assert Tq % blk_q == 0 and Tk % blk_k == 0
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     nq, nk = Tq // blk_q, Tk // blk_k
 
-    grid = (B, H, nq, nk)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k, nk=nk
     )
+    qspec = pl.BlockSpec((_SQ, _SQ, blk_q, D), lambda b, h, qi, ki: (b, h, qi, 0))
+    kspec = pl.BlockSpec((_SQ, _SQ, blk_k, D), lambda b, h, qi, ki: (b, h, ki, 0))
+    stat = pl.BlockSpec((_SQ, _SQ, blk_q, 1), lambda b, h, qi, ki: (b, h, qi, 0))
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, blk_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, D), lambda b, h, qi, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, blk_k, 1, D), lambda b, h, qi, ki: (b, ki, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda b, h, qi, ki: (b, h, qi)),
-        ],
+        grid=(B, H, nq, nk),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, stat],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tq, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, D), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -125,29 +141,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = (ki * blk_k <= qi * blk_q + blk_q - 1) if causal else (ki >= 0)
-
-    @pl.when(run)
+    @pl.when(_runs(qi, ki, causal=causal, blk_q=blk_q, blk_k=blk_k))
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        s = q @ k.T
-        if causal:
-            qpos = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = do @ v.T
-        ds = p * (dp - delta[:, None]) * scale
-        dq_scr[...] += ds @ k
+        k = k_ref[...]
+        s = _scores(q_ref[...], k, qi, ki, scale=scale,
+                    causal=causal, blk_q=blk_q, blk_k=blk_k)
+        p = jnp.exp(s - lse_ref[...])
+        dp = _dot(do_ref[...], v_ref[...], _NT)
+        ds = p * (dp - delta_ref[...]) * scale
+        dq_scr[...] += _dot(ds.astype(k.dtype), k)
 
     @pl.when(ki == nk - 1)
     def _done():
-        dq_ref[0, :, 0, :] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -161,66 +167,58 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    run = (qi * blk_q + blk_q - 1 >= ki * blk_k) if causal else (qi >= 0)
-
-    @pl.when(run)
+    @pl.when(_runs(qi, ki, causal=causal, blk_q=blk_q, blk_k=blk_k))
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        s = q @ k.T                                            # (bq, bk)
-        if causal:
-            qpos = qi * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-            kpos = ki * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dv_scr[...] += p.T @ do
-        dp = do @ v.T
-        ds = p * (dp - delta[:, None]) * scale  # one factor of scale total
-        dk_scr[...] += ds.T @ (q / scale)       # q_ref was pre-scaled; undo
+        q, do = q_ref[...], do_ref[...]
+        s = _scores(q, k_ref[...], qi, ki, scale=scale,
+                    causal=causal, blk_q=blk_q, blk_k=blk_k)
+        p = jnp.exp(s - lse_ref[...])                          # (bq, bk)
+        dv_scr[...] += _dot(p.astype(do.dtype), do, _TN)
+        dp = _dot(do, v_ref[...], _NT)
+        ds = p * (dp - delta_ref[...]) * scale
+        dk_scr[...] += _dot(ds.astype(q.dtype), q, _TN)
 
     @pl.when(qi == nq - 1)
     def _done():
-        dk_ref[0, :, 0, :] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, scale=None,
                         blk_q=128, blk_k=128, interpret=False):
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
     blk_q = min(blk_q, Tq)
     blk_k = min(blk_k, Tk)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     nq, nk = Tq // blk_q, Tk // blk_k
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    )
 
     # delta = rowsum(dO * O): cheap, done outside the kernels.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = delta.transpose(0, 2, 1)  # (B, H, Tq)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)                             # (B, H, Tq, 1)
 
-    qspec = pl.BlockSpec((1, blk_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0))
-    kspec = pl.BlockSpec((1, blk_k, 1, D), lambda b, h, qi, ki: (b, ki, h, 0))
-    statq = pl.BlockSpec((1, 1, blk_q), lambda b, h, qi, ki: (b, h, qi))
+    qspec = pl.BlockSpec((_SQ, _SQ, blk_q, D), lambda b, h, qi, ki: (b, h, qi, 0))
+    kspec = pl.BlockSpec((_SQ, _SQ, blk_k, D), lambda b, h, qi, ki: (b, h, ki, 0))
+    statq = pl.BlockSpec((_SQ, _SQ, blk_q, 1), lambda b, h, qi, ki: (b, h, qi, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k, nk=nk),
         grid=(B, H, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, statq, statq],
-        out_specs=[qspec],
-        out_shape=[jax.ShapeDtypeStruct((B, Tq, H, D), q.dtype)],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=params,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)[0]
+        name="flash_attention_dq",
+    )(q, k, v, do, lse, delta)
 
-    qspec2 = pl.BlockSpec((1, blk_q, 1, D), lambda b, h, ki, qi: (b, qi, h, 0))
-    kspec2 = pl.BlockSpec((1, blk_k, 1, D), lambda b, h, ki, qi: (b, ki, h, 0))
-    statq2 = pl.BlockSpec((1, 1, blk_q), lambda b, h, ki, qi: (b, h, qi))
+    qspec2 = pl.BlockSpec((_SQ, _SQ, blk_q, D), lambda b, h, ki, qi: (b, h, qi, 0))
+    kspec2 = pl.BlockSpec((_SQ, _SQ, blk_k, D), lambda b, h, ki, qi: (b, h, ki, 0))
+    statq2 = pl.BlockSpec((_SQ, _SQ, blk_q, 1), lambda b, h, ki, qi: (b, h, qi, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k, nq=nq),
@@ -228,16 +226,15 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, scale=None,
         in_specs=[qspec2, kspec2, kspec2, qspec2, statq2, statq2],
         out_specs=[kspec2, kspec2],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tk, H, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Tk, H, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, D), jnp.float32),
             pltpu.VMEM((blk_k, D), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=params,
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

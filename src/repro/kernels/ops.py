@@ -12,8 +12,6 @@ lowers the FLOP-equivalent ref path instead (kernels are TPU-targeted).
 from __future__ import annotations
 
 import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -27,10 +25,22 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _repeat_kv(k, group):
+def _repeat_kv(k, group, axis):
     if group == 1:
         return k
-    return jnp.repeat(k, group, axis=2)
+    return jnp.repeat(k, group, axis=axis)
+
+
+def _heads_major(x):
+    """(B, T, H, D) <-> (B, H, T, D): the flash kernels tile (rows, D) per
+    head."""
+    return x.transpose(0, 2, 1, 3)
+
+
+def _mha_inputs(q, k, v):
+    group = q.shape[2] // k.shape[2]
+    return (_heads_major(q), _repeat_kv(_heads_major(k), group, 1),
+            _repeat_kv(_heads_major(v), group, 1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -41,27 +51,25 @@ def flash_attention(q, k, v, causal=True, blk_q=128, blk_k=128):
 
 
 def _flash_fwd(q, k, v, causal, blk_q, blk_k):
-    group = q.shape[2] // k.shape[2]
-    kr, vr = _repeat_kv(k, group), _repeat_kv(v, group)
     o, lse = _fa.flash_attention_fwd(
-        q, kr, vr, causal=causal, blk_q=blk_q, blk_k=blk_k, interpret=_interpret()
+        *_mha_inputs(q, k, v), causal=causal, blk_q=blk_q, blk_k=blk_k,
+        interpret=_interpret(),
     )
-    return o, (q, k, v, o, lse)
+    return _heads_major(o), (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, blk_q, blk_k, res, do):
     q, k, v, o, lse = res
-    group = q.shape[2] // k.shape[2]
-    kr, vr = _repeat_kv(k, group), _repeat_kv(v, group)
     dq, dk, dv = _fa.flash_attention_bwd(
-        q, kr, vr, o, lse, do, causal=causal, blk_q=blk_q, blk_k=blk_k,
-        interpret=_interpret(),
+        *_mha_inputs(q, k, v), o, lse, _heads_major(do), causal=causal,
+        blk_q=blk_q, blk_k=blk_k, interpret=_interpret(),
     )
-    if group > 1:
-        B, T, Hq, D = dk.shape
-        dk = dk.reshape(B, T, Hq // group, group, D).sum(axis=3)
-        dv = dv.reshape(B, T, Hq // group, group, D).sum(axis=3)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    B, Hq, T, D = dk.shape
+    group = Hq // k.shape[2]
+    # Group-sum the repeated kv heads back to Hkv, then back to (B,T,H,D).
+    dk = _heads_major(dk.reshape(B, Hq // group, group, T, D).sum(axis=2))
+    dv = _heads_major(dv.reshape(B, Hq // group, group, T, D).sum(axis=2))
+    return _heads_major(dq), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -70,7 +78,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def decode_attention(q, k, v, kv_len, *, blk_s: int = 512):
     """q: (B,Hq,D) single token; k, v: (B,S,Hkv,D); kv_len: (B,)."""
     group = q.shape[1] // k.shape[2]
-    kr, vr = _repeat_kv(k, group), _repeat_kv(v, group)
+    kr, vr = _repeat_kv(k, group, 2), _repeat_kv(v, group, 2)
     acc, m, l = _dec.decode_attention_splits(
         q, kr, vr, kv_len, blk_s=blk_s, interpret=_interpret()
     )

@@ -40,10 +40,11 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, blk_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale)
     if pad:
         out = out[:rows]

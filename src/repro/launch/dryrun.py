@@ -1,15 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512"
-    # XLA:CPU-only workaround: the all-reduce-promotion pass CHECK-crashes
-    # cloning reducers that layout assignment gave a copy root (our fused
-    # psum tuples). Promotion is a CPU numerics nicety; TPU lowers the same
-    # HLO without it. See DESIGN.md §Notes.
-    " --xla_disable_hlo_passes=all-reduce-promotion"
-)
-# ^ MUST precede every other import (jax locks the device count on first
-# init). Dry-run only — never set globally.
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract the roofline terms from the compiled artifact.
 
@@ -26,6 +14,21 @@ Success of this script for every cell on BOTH meshes is the multi-pod
 dry-run deliverable: it proves the sharding config is coherent (no
 mismatched specs, no OOM-at-compile, no unsupported collective).
 """
+import os
+
+if __name__ == "__main__":
+    # Set only when run as a script, before jax is imported (jax locks the
+    # device count on first init); importing this module leaves the
+    # caller's XLA_FLAGS alone.
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512"
+        # XLA:CPU-only workaround: the all-reduce-promotion pass CHECK-crashes
+        # cloning reducers that layout assignment gave a copy root (our fused
+        # psum tuples). Promotion is a CPU numerics nicety; TPU lowers the
+        # same HLO without it. See DESIGN.md §Notes.
+        " --xla_disable_hlo_passes=all-reduce-promotion"
+    )
+
 import argparse
 import json
 import re

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import zoo
 from repro.runtime import spmd
@@ -29,10 +30,11 @@ def main() -> int:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.configure()
 
     cfg = registry.get(args.arch, reduced=args.reduced)
     mesh = make_host_mesh()
-    model = zoo.build(cfg, dtype=jnp.float32)
+    model = zoo.build(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
     max_len = args.prompt_len + args.gen
     prefill_fn, decode_fn = spmd.build_serve_fns(model, mesh, max_len)

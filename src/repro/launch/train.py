@@ -16,6 +16,7 @@ import json
 import jax.numpy as jnp
 
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.controlplane import ControlPlane
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -36,6 +37,7 @@ def main() -> int:
                     help="control-plane group size (0 = no control plane)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.configure()
 
     arch = registry.get(args.arch, reduced=args.reduced)
     cfg = TrainerConfig(

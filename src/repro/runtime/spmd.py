@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.collective import classic_track_commit, fast_quorum_size
 from repro.optim import adamw, compression
 from repro.runtime import sharding as shd
@@ -115,12 +114,6 @@ def build_train_step(
     dp_axes = shd.batch_axes(mesh)
     M = _dp_size(mesh)
     fq = fast_quorum_size(M)
-    auto_axes = tuple(a for a in mesh.axis_names if a not in dp_axes)
-    if auto_axes and any(mesh.shape[a] > 1 for a in auto_axes):
-        # Manual-DP x auto-TP needs a partitioner that understands manual
-        # subgroups; on legacy jax that means flipping to Shardy (see
-        # compat.ensure_partial_auto_partitioner).
-        compat.ensure_partial_auto_partitioner()
     specs = state_specs(model, opt_cfg, mesh, compress_pod)
     p_specs = specs.params
 
@@ -139,7 +132,7 @@ def build_train_step(
                 if d is not None:
                     p = jax.lax.all_gather(p, "data", axis=d, tiled=True)
                 pin = shd.strip_axis(sub, "data")
-                if any(e is not None for e in pin) and compat.wsc_in_partial_manual_ok():
+                if any(e is not None for e in pin):
                     p = jax.lax.with_sharding_constraint(
                         p, NamedSharding(mesh, pin)
                     )
@@ -351,7 +344,7 @@ def build_train_step(
 
     def wrapped(state, batch):
         bs = batch_specs_of(batch)
-        f = compat.shard_map(
+        f = jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(state_manual, bs),

@@ -22,6 +22,7 @@ from jax.sharding import Mesh
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ArchConfig
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.mesh import make_host_mesh
 from repro.models import zoo
 from repro.optim import adamw
 from repro.runtime import sharding as shd
@@ -56,10 +57,11 @@ class Trainer:
         host_id: str = "host0",
     ):
         self.cfg = cfg
-        self.mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh or make_host_mesh()
         self.control = control
         self.host_id = host_id
         self.model = zoo.build(cfg.arch, dtype=cfg.dtype)
+        self.state: Optional[spmd.TrainState] = None  # after train()
         self.step_fn, self.state_shardings, self.batch_shard_fn = spmd.build_train_step(
             self.model, cfg.opt, self.mesh, track=cfg.track,
             compress_pod=cfg.compress_pod,
@@ -121,7 +123,7 @@ class Trainer:
             for i in range(start_step, cfg.steps):
                 t0 = time.perf_counter()
                 raw = next(it)
-                batch = self._to_model_batch(raw)
+                batch = self.place_batch(raw)
                 state, metrics = self.step_fn(state, batch)
                 m = {k: float(v) for k, v in metrics.items()}
                 m["wall_ms"] = (time.perf_counter() - t0) * 1e3
@@ -134,17 +136,20 @@ class Trainer:
             if self.ckpt:
                 self.ckpt.save(cfg.steps, {"state": state}, async_=False)
                 self.ckpt.wait()
+        self.state = state
         return logs
 
-    def _to_model_batch(self, raw: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
+    def place_batch(self, raw: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
+        """Host batch -> device arrays, each split over the mesh's batch
+        axes as ``batch_shard_fn`` lays it out (one slice per replica)."""
         batch = {}
         for k, v in raw.items():
             if k == "embeddings":
-                batch[k] = jnp.asarray(v, self.cfg.dtype)
+                batch[k] = np.asarray(v, self.cfg.dtype)
             elif k == "loss_mask":
-                batch[k] = jnp.asarray(v, jnp.float32)
+                batch[k] = np.asarray(v, np.float32)
             else:
-                batch[k] = jnp.asarray(v)
+                batch[k] = np.asarray(v)
         if self.cfg.arch.frontend is not None:
             batch.pop("tokens", None)
-        return batch
+        return jax.device_put(batch, self.batch_shard_fn(batch))

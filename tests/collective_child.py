@@ -10,8 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.collective import (
     classic_track_commit,
     consensus_gradient_sync,
@@ -19,13 +17,14 @@ from repro.core.collective import (
     masked_update,
     voted_psum,
 )
+from repro.launch.mesh import make_mesh
 
 assert len(jax.devices()) == 8, jax.devices()
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 
 def run_votes(fn, votes):
-    f = shard_map(
+    f = jax.shard_map(
         lambda v: fn(v[0], ("data",)),
         mesh=mesh,
         in_specs=P("data"),
@@ -52,7 +51,7 @@ def vp(x, v):
     tree, n_yes, committed = voted_psum({"g": x[0]}, v[0], ("data",))
     return tree["g"], n_yes, committed
 
-f = shard_map(vp, mesh=mesh, in_specs=(P("data"), P("data")), out_specs=(P(), P(), P()), check_vma=False)
+f = jax.shard_map(vp, mesh=mesh, in_specs=(P("data"), P("data")), out_specs=(P(), P(), P()), check_vma=False)
 g, n_yes, committed = jax.jit(f)(
     jnp.arange(8, dtype=jnp.float32), jnp.ones(8, jnp.float32)
 )
@@ -72,7 +71,7 @@ def sync(g):
     mean, n_yes, committed = consensus_gradient_sync(grads, ("data",), track="fast")
     return mean["w"], n_yes, committed
 
-f2 = shard_map(sync, mesh=mesh, in_specs=P("data"), out_specs=(P(), P(), P()), check_vma=False)
+f2 = jax.shard_map(sync, mesh=mesh, in_specs=P("data"), out_specs=(P(), P(), P()), check_vma=False)
 g = jnp.ones((8, 4), jnp.float32)
 g = g.at[3].set(jnp.nan)  # replica 3 diverged
 mean, n_yes, committed = jax.jit(f2)(g)

@@ -16,14 +16,14 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
 from repro.configs import registry
 from repro.launch.dryrun import parse_collectives, _shaped
+from repro.launch.mesh import make_mesh
 from repro.models import zoo
 from repro.optim.adamw import AdamWConfig
 from repro.runtime import sharding as shd, spmd
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 for arch in ("qwen3-1.7b", "jamba-v0.1-52b", "xlstm-1.3b"):
     cfg = registry.get(arch, reduced=True)
@@ -43,7 +43,7 @@ for arch in ("qwen3-1.7b", "jamba-v0.1-52b", "xlstm-1.3b"):
     batch["loss_mask"] = jax.ShapeDtypeStruct(
         (B, T), jnp.float32, sharding=NamedSharding(mesh, P("data", None)))
     compiled = step_fn.lower(structs, batch).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text(), 8)
     assert cost.get("flops", 0) > 0, (arch, cost)
     assert coll["total_bytes"] > 0, (arch, "no collectives found")
@@ -62,7 +62,7 @@ for arch in ("qwen3-1.7b", "jamba-v0.1-52b", "xlstm-1.3b"):
     dbatch = {"tokens": jax.ShapeDtypeStruct(
         (8, 1), jnp.int32, sharding=NamedSharding(mesh, P("data", None)))}
     dec = jax.jit(model.decode_step).lower(p_structs, c_structs, dbatch).compile()
-    assert compat.cost_analysis(dec).get("flops", 0) > 0
+    assert dec.cost_analysis().get("flops", 0) > 0
     print(f"{arch}: decode OK")
 
 print("DRYRUN-SMALL-OK")

@@ -102,6 +102,17 @@ def test_checkpoint_roundtrip_and_gc(tmp_path):
     np.testing.assert_allclose(np.asarray(out["state"]["w"]), np.asarray(tree["w"]) * 3)
 
 
+def test_checkpoint_roundtrip_bf16(tmp_path):
+    """.npy has no bf16 type: the raw records must come back as bf16."""
+    mgr = CheckpointManager(str(tmp_path))
+    tree = {"w": (jnp.arange(6.0) / 7).astype(jnp.bfloat16)}
+    mgr.save(1, {"state": tree}, async_=False)
+    _, out = mgr.restore({"state": tree})
+    assert out["state"]["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out["state"]["w"], np.float32),
+                                  np.asarray(tree["w"], np.float32))
+
+
 def test_checkpoint_uncommitted_invisible(tmp_path):
     """If the consensus commit fails, the checkpoint must not exist."""
     mgr = CheckpointManager(str(tmp_path), commit_fn=lambda rec: False)
