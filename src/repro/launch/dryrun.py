@@ -1,5 +1,5 @@
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
-production mesh and extract the roofline terms from the compiled artifact.
+production mesh and extract its cost terms from the compiled artifact.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b \
       --shape train_4k --mesh single
@@ -7,8 +7,9 @@ production mesh and extract the roofline terms from the compiled artifact.
 
 Per cell this produces artifacts/dryrun/<mesh>/<arch>__<shape>.json with:
   memory_analysis, cost_analysis (per-device HLO FLOPs/bytes), the summed
-  collective-bytes table parsed from the post-SPMD HLO, and timing. The
-  roofline builder (benchmarks/roofline.py) reads these artifacts.
+  collective-bytes table parsed from the post-SPMD HLO, and timing, for
+  reading by hand: these are projections from the compiler, not chip
+  measurements (those are benchmarks/chip's).
 
 Success of this script for every cell on BOTH meshes is the multi-pod
 dry-run deliverable: it proves the sharding config is coherent (no

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.runtime.tracing import scope
 
 Params = Dict[str, Any]
 
@@ -251,6 +252,7 @@ def chunked_attention(q, k, v, *, causal, q_offset=None, kv_len=None,
 CHUNKED_ATTN_THRESHOLD = 1024  # use tiled path at/above this many kv tokens
 
 
+@scope("attention")
 def attention(
     cfg: ArchConfig,
     p: Params,
@@ -287,12 +289,13 @@ def attention(
         new_cache = None
     else:
         idx = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1), (B,))
-        k_cache = jax.vmap(lambda c, kn, i: jax.lax.dynamic_update_slice(c, kn, (i, 0, 0)))(
-            cache["k"], k, idx
-        )
-        v_cache = jax.vmap(lambda c, vn, i: jax.lax.dynamic_update_slice(c, vn, (i, 0, 0)))(
-            cache["v"], v, idx
-        )
+        with scope("kv_update"):
+            k_cache = jax.vmap(lambda c, kn, i: jax.lax.dynamic_update_slice(c, kn, (i, 0, 0)))(
+                cache["k"], k, idx
+            )
+            v_cache = jax.vmap(lambda c, vn, i: jax.lax.dynamic_update_slice(c, vn, (i, 0, 0)))(
+                cache["v"], v, idx
+            )
         # Causal over the cache: query t (global position idx+t) sees keys
         # [0, idx+t]; kv_len hides never-written slots.
         if k_cache.shape[1] >= CHUNKED_ATTN_THRESHOLD:
@@ -332,6 +335,7 @@ def init_ffn(cfg: ArchConfig, rng, dtype=jnp.bfloat16) -> Params:
     }
 
 
+@scope("ffn")
 def apply_ffn(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.activation == "swiglu":
         return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import transformer as T
+from repro.runtime.tracing import scope
 
 Params = Dict[str, Any]
 
@@ -43,6 +44,7 @@ class Model:
 
     # ----------------------------------------------------------- embeddings
 
+    @scope("embed")
     def _embed(self, params: Params, batch: Dict[str, jax.Array],
                pos_offset: jax.Array | int = 0) -> jax.Array:
         if "embeddings" in batch:
@@ -55,7 +57,10 @@ class Model:
             h = h + L.embed_lookup(params["embed"]["pos"], idx)[None]
         return h
 
+    @scope("head")
     def _head(self, params: Params, h: jax.Array) -> jax.Array:
+        """The final norm, then the vocabulary projection."""
+        h = L.apply_norm(self.cfg, params["final_norm"], h)
         if self.cfg.tie_embeddings:
             return h @ params["embed"]["tok"].T
         return h @ params["embed"]["head"]
@@ -67,7 +72,6 @@ class Model:
         h = self._embed(params, batch)
         h, aux, _ = T.apply_stack(self.cfg, params["stack"], h, train=train,
                                   gather_fn=gather_fn)
-        h = L.apply_norm(self.cfg, params["final_norm"], h)
         logits = self._head(params, h)
         return logits, aux
 
@@ -75,13 +79,14 @@ class Model:
              gather_fn=None) -> Tuple[jax.Array, Dict]:
         logits, aux = self.forward(params, batch, train=True, gather_fn=gather_fn)
         labels = batch["labels"]
-        lf = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
-        mask = batch.get("loss_mask")
-        if mask is None:
-            ce = jnp.mean(nll)
-        else:
-            ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with scope("head"):
+            lf = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
+            mask = batch.get("loss_mask")
+            if mask is None:
+                ce = jnp.mean(nll)
+            else:
+                ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         total = ce + sum(aux.values())
         metrics = {"ce": ce, **aux}
         return total, metrics
@@ -110,7 +115,6 @@ class Model:
             caches=cache["layers"], cache_pos=jnp.zeros((), jnp.int32),
             train=False,
         )
-        h = L.apply_norm(self.cfg, params["final_norm"], h)
         logits = self._head(params, h[:, -1:])[:, 0]
         Tn = (batch.get("tokens", batch.get("embeddings"))).shape[1]
         return logits, {"layers": new_layers, "pos": jnp.asarray(Tn, jnp.int32)}
@@ -124,7 +128,6 @@ class Model:
             self.cfg, params["stack"], h,
             positions=None, caches=cache["layers"], cache_pos=pos, train=False,
         )
-        h = L.apply_norm(self.cfg, params["final_norm"], h)
         logits = self._head(params, h[:, -1:])[:, 0]
         return logits, {"layers": new_layers, "pos": pos + h.shape[1]}
 

@@ -26,6 +26,7 @@ from repro.core.metrics import Recorder
 from repro.core.sim import Cluster
 from repro.core.types import EntryId
 from repro.data.pipeline import ShardLease
+from repro.runtime import tracing
 
 
 class ControlPlane:
@@ -63,13 +64,14 @@ class ControlPlane:
     def propose_and_wait(self, command: str, timeout: float = 60_000.0) -> bool:
         """Propose through a NON-leader node (exercises the fast track) and
         run the simulated group until commit."""
-        lead = self.cluster.leader() or self.cluster.run_until_leader(60_000)
-        others = [n for n in self.cluster.nodes if n != lead]
-        via = others[0] if others else lead
-        eid = self.cluster.submit(command, via=via)
-        ok = self.cluster.run_until_committed([eid], timeout)
-        if ok:
-            self.cluster.run(50)  # let applies propagate to the watch node
+        with tracing.span("control.commit", kind=command.split(":", 1)[0]):
+            lead = self.cluster.leader() or self.cluster.run_until_leader(60_000)
+            others = [n for n in self.cluster.nodes if n != lead]
+            via = others[0] if others else lead
+            eid = self.cluster.submit(command, via=via)
+            ok = self.cluster.run_until_committed([eid], timeout)
+            if ok:
+                self.cluster.run(50)  # let applies propagate to the watch node
         return ok
 
     def _on_apply(self, cmd: Any) -> None:

@@ -36,6 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.collective import classic_track_commit, fast_quorum_size
 from repro.optim import adamw, compression
 from repro.runtime import sharding as shd
+from repro.runtime.tracing import scope
 
 Params = Any
 
@@ -145,51 +146,53 @@ def build_train_step(
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, jax.Array]]:
         params = state.params
 
-        if fsdp_stream:
-            rest = {k: v for k, v in params.items() if k != "stack"}
-            rest_specs = {k: p_specs[k] for k in rest}
-            rest_full = _gather_params(rest, rest_specs)
-            gather_fn = make_gather_fn(p_specs["stack"])
+        with scope("train/fwd_bwd"):
+            if fsdp_stream:
+                rest = {k: v for k, v in params.items() if k != "stack"}
+                rest_specs = {k: p_specs[k] for k in rest}
+                rest_full = _gather_params(rest, rest_specs)
+                gather_fn = make_gather_fn(p_specs["stack"])
 
-            def loss_fn(diff):
-                rf, local_stack = diff
-                p = dict(rf)
-                p["stack"] = local_stack
-                return model.loss(p, batch, gather_fn=gather_fn)
+                def loss_fn(diff):
+                    rf, local_stack = diff
+                    p = dict(rf)
+                    p["stack"] = local_stack
+                    return model.loss(p, batch, gather_fn=gather_fn)
 
-            (loss, metrics), (g_rest, g_stack) = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )((rest_full, params["stack"]))
-            # g_stack is ALREADY reduce-scattered+summed over "data" (gather
-            # transpose); g_rest is per-replica and full-shaped.
-            grads = dict(g_rest)
-            grads["stack"] = g_stack
-            prereduction = {k: g_rest[k] for k in g_rest}
-        else:
-            full_params = _gather_params(params, p_specs)
+                (loss, metrics), (g_rest, g_stack) = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )((rest_full, params["stack"]))
+                # g_stack is ALREADY reduce-scattered+summed over "data" (gather
+                # transpose); g_rest is per-replica and full-shaped.
+                grads = dict(g_rest)
+                grads["stack"] = g_stack
+                prereduction = {k: g_rest[k] for k in g_rest}
+            else:
+                full_params = _gather_params(params, p_specs)
 
-            def loss_fn(fp):
-                return model.loss(fp, batch)
+                def loss_fn(fp):
+                    return model.loss(fp, batch)
 
-            (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                full_params
-            )
-            prereduction = grads
+                (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                    full_params
+                )
+                prereduction = grads
 
         # --- Fast Raft vote: this replica's local signals.
-        finite = jnp.isfinite(loss)
-        sq = jnp.asarray(0.0, jnp.float32)
-        for g in jax.tree_util.tree_leaves(prereduction):
-            finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
-            sq = sq + jnp.sum(jnp.square(g.astype(jnp.float32)))
-        vote = jnp.logical_and(finite, jnp.sqrt(sq) < vote_max_norm).astype(jnp.float32)
+        with scope("train/vote"):
+            finite = jnp.isfinite(loss)
+            sq = jnp.asarray(0.0, jnp.float32)
+            for g in jax.tree_util.tree_leaves(prereduction):
+                finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
+                sq = sq + jnp.sum(jnp.square(g.astype(jnp.float32)))
+            vote = jnp.logical_and(finite, jnp.sqrt(sq) < vote_max_norm).astype(jnp.float32)
 
-        if track == "classic":
-            # Baseline: two dedicated vote rounds before the reduction.
-            n_yes, committed = classic_track_commit(vote, dp_axes)
-            # classic commits on majority; hold it to the same fast quorum for
-            # an apples-to-apples gate.
-            committed = n_yes >= jnp.asarray(fq, n_yes.dtype)
+            if track == "classic":
+                # Baseline: two dedicated vote rounds before the reduction.
+                n_yes, committed = classic_track_commit(vote, dp_axes)
+                # classic commits on majority; hold it to the same fast quorum
+                # for an apples-to-apples gate.
+                committed = n_yes >= jnp.asarray(fq, n_yes.dtype)
 
         # --- Gradient reduction phase.
         flat, treedef = jax.tree_util.tree_flatten_with_path(grads)
@@ -210,96 +213,100 @@ def build_train_step(
 
         # Per-replica Fast Raft gate on every PRE-reduction leaf: a replica
         # that voted 0 contributes exactly nothing to the committed update.
-        flat = [
-            (path, g if already_reduced(path, s)
-             else (jnp.nan_to_num(g.astype(jnp.float32)) * vote).astype(g.dtype))
-            for (path, g), s in zip(flat, spec_flat)
-        ]
+        with scope("train/vote"):
+            flat = [
+                (path, g if already_reduced(path, s)
+                 else (jnp.nan_to_num(g.astype(jnp.float32)) * vote).astype(g.dtype))
+                for (path, g), s in zip(flat, spec_flat)
+            ]
 
-        fsdp_items = [(i, shd.fsdp_dim(s)) for i, s in enumerate(spec_flat)]
-        reduced: list = [None] * len(flat)
+        with scope("train/reduce"):
+            fsdp_items = [(i, shd.fsdp_dim(s)) for i, s in enumerate(spec_flat)]
+            reduced: list = [None] * len(flat)
 
-        # Non-FSDP, per-replica leaves + the vote ride ONE fused psum (the
-        # fast track).
-        plain_idx = [i for i, d in fsdp_items if d is None]
-        plain = tuple(flat[i][1] for i in plain_idx)
-        if track == "fast":
-            out = jax.lax.psum((*plain, vote), dp_axes)
-            *plain_out, n_yes = out
-            committed = n_yes >= jnp.asarray(fq, n_yes.dtype)
-        else:
-            plain_out = list(jax.lax.psum(plain, dp_axes)) if plain else []
-        for i, g in zip(plain_idx, plain_out):
-            reduced[i] = g
+            # Non-FSDP, per-replica leaves + the vote ride ONE fused psum (the
+            # fast track).
+            plain_idx = [i for i, d in fsdp_items if d is None]
+            plain = tuple(flat[i][1] for i in plain_idx)
+            if track == "fast":
+                out = jax.lax.psum((*plain, vote), dp_axes)
+                *plain_out, n_yes = out
+                committed = n_yes >= jnp.asarray(fq, n_yes.dtype)
+            else:
+                plain_out = list(jax.lax.psum(plain, dp_axes)) if plain else []
+            for i, g in zip(plain_idx, plain_out):
+                reduced[i] = g
 
-        # FSDP leaves: reduce_scatter over "data" (unless the streaming
-        # gather transpose already did it), then the cross-pod hop
-        # (optionally int8 + error feedback on the DCN link).
-        ef_leaves = (
-            jax.tree_util.tree_flatten_with_path(state.ef_residual)[0]
-            if state.ef_residual is not None else None
-        )
-        new_ef_flat: Dict[int, jax.Array] = {}
-        for i, d in fsdp_items:
-            path, g = flat[i]
-            if d is None:
-                continue  # handled in the fused psum above
-            pre_done = already_reduced(path, spec_flat[i])
-            if (not pre_done) and "data" in dp_axes and mesh.shape["data"] > 1:
-                g = jax.lax.psum_scatter(g, "data", scatter_dimension=d, tiled=True)
-            if "pod" in dp_axes:
-                if compress_pod and ef_leaves is not None:
-                    gf = g.astype(jnp.float32) + ef_leaves[i][1]
-                    scale = jnp.maximum(jnp.max(jnp.abs(gf)), 1e-12) / 127.0
-                    q = jnp.clip(jnp.round(gf / scale), -127, 127).astype(jnp.int8)
-                    new_ef_flat[i] = gf - q.astype(jnp.float32) * scale
-                    qs = jax.lax.all_gather(q, "pod")          # int8 on DCN
-                    ss = jax.lax.all_gather(scale, "pod")
-                    g = jnp.sum(
-                        qs.astype(jnp.float32) * ss.reshape((-1,) + (1,) * g.ndim),
-                        axis=0,
-                    ).astype(g.dtype)
-                else:
-                    g = jax.lax.psum(g, "pod")
-            reduced[i] = g
-        if state.ef_residual is not None:
-            old_flat, ef_def = jax.tree_util.tree_flatten(state.ef_residual)
-            new_ef = jax.tree_util.tree_unflatten(
-                ef_def,
-                [new_ef_flat.get(i, old_flat[i]) for i in range(len(old_flat))],
+            # FSDP leaves: reduce_scatter over "data" (unless the streaming
+            # gather transpose already did it), then the cross-pod hop
+            # (optionally int8 + error feedback on the DCN link).
+            ef_leaves = (
+                jax.tree_util.tree_flatten_with_path(state.ef_residual)[0]
+                if state.ef_residual is not None else None
             )
-        else:
-            new_ef = None
+            new_ef_flat: Dict[int, jax.Array] = {}
+            for i, d in fsdp_items:
+                path, g = flat[i]
+                if d is None:
+                    continue  # handled in the fused psum above
+                pre_done = already_reduced(path, spec_flat[i])
+                if (not pre_done) and "data" in dp_axes and mesh.shape["data"] > 1:
+                    g = jax.lax.psum_scatter(g, "data", scatter_dimension=d, tiled=True)
+                if "pod" in dp_axes:
+                    if compress_pod and ef_leaves is not None:
+                        gf = g.astype(jnp.float32) + ef_leaves[i][1]
+                        scale = jnp.maximum(jnp.max(jnp.abs(gf)), 1e-12) / 127.0
+                        q = jnp.clip(jnp.round(gf / scale), -127, 127).astype(jnp.int8)
+                        new_ef_flat[i] = gf - q.astype(jnp.float32) * scale
+                        qs = jax.lax.all_gather(q, "pod")          # int8 on DCN
+                        ss = jax.lax.all_gather(scale, "pod")
+                        g = jnp.sum(
+                            qs.astype(jnp.float32) * ss.reshape((-1,) + (1,) * g.ndim),
+                            axis=0,
+                        ).astype(g.dtype)
+                    else:
+                        g = jax.lax.psum(g, "pod")
+                reduced[i] = g
+            if state.ef_residual is not None:
+                old_flat, ef_def = jax.tree_util.tree_flatten(state.ef_residual)
+                new_ef = jax.tree_util.tree_unflatten(
+                    ef_def,
+                    [new_ef_flat.get(i, old_flat[i]) for i in range(len(old_flat))],
+                )
+            else:
+                new_ef = None
 
-        grads_r = jax.tree_util.tree_unflatten(
-            treedef, reduced
-        )
-        denom = jnp.maximum(n_yes, 1.0)
-        grads_r = jax.tree_util.tree_map(lambda g: g / denom.astype(g.dtype), grads_r)
+            grads_r = jax.tree_util.tree_unflatten(
+                treedef, reduced
+            )
+            denom = jnp.maximum(n_yes, 1.0)
+            grads_r = jax.tree_util.tree_map(lambda g: g / denom.astype(g.dtype), grads_r)
 
         # Global rollback condition: quorum AND post-reduction finiteness
         # (catches poisoned contributions inside the streamed reductions).
-        all_finite = jnp.asarray(True)
-        for g in jax.tree_util.tree_leaves(grads_r):
-            all_finite = jnp.logical_and(all_finite, jnp.all(jnp.isfinite(g)))
-        committed = jnp.logical_and(committed, all_finite)
+        with scope("train/clip"):
+            all_finite = jnp.asarray(True)
+            for g in jax.tree_util.tree_leaves(grads_r):
+                all_finite = jnp.logical_and(all_finite, jnp.all(jnp.isfinite(g)))
+            committed = jnp.logical_and(committed, all_finite)
 
-        # Global grad norm for clipping (scalar psum over FSDP shards).
-        local_sq = jnp.asarray(0.0, jnp.float32)
-        repl_sq = jnp.asarray(0.0, jnp.float32)
-        flat_r = jax.tree_util.tree_flatten_with_path(grads_r)[0]
-        for (path, g), s in zip(flat_r, spec_flat):
-            gs = jnp.sum(jnp.square(g.astype(jnp.float32)))
-            if shd.fsdp_dim(s) is None:
-                repl_sq = repl_sq + gs
-            else:
-                local_sq = local_sq + gs
-        grad_norm = jnp.sqrt(repl_sq + jax.lax.psum(local_sq, ("data",) if "data" in dp_axes else dp_axes))
+            # Global grad norm for clipping (scalar psum over FSDP shards).
+            local_sq = jnp.asarray(0.0, jnp.float32)
+            repl_sq = jnp.asarray(0.0, jnp.float32)
+            flat_r = jax.tree_util.tree_flatten_with_path(grads_r)[0]
+            for (path, g), s in zip(flat_r, spec_flat):
+                gs = jnp.sum(jnp.square(g.astype(jnp.float32)))
+                if shd.fsdp_dim(s) is None:
+                    repl_sq = repl_sq + gs
+                else:
+                    local_sq = local_sq + gs
+            grad_norm = jnp.sqrt(repl_sq + jax.lax.psum(local_sq, ("data",) if "data" in dp_axes else dp_axes))
 
         # --- Sharded AdamW on local shards; quorum-gated apply.
-        new_params, new_opt = adamw.update(
-            opt_cfg, grads_r, state.opt, params, grad_norm=grad_norm
-        )
+        with scope("train/adamw"):
+            new_params, new_opt = adamw.update(
+                opt_cfg, grads_r, state.opt, params, grad_norm=grad_norm
+            )
         c = committed.astype(jnp.float32)
 
         def gate(new, old):
@@ -309,14 +316,15 @@ def build_train_step(
                 new, old,
             )
 
-        params_out = gate(new_params, params)
-        opt_out = adamw.OptState(
-            m=gate(new_opt.m, state.opt.m),
-            v=gate(new_opt.v, state.opt.v),
-            master=gate(new_opt.master, state.opt.master)
-            if state.opt.master is not None else None,
-            step=state.opt.step + committed.astype(jnp.int32),
-        )
+        with scope("train/gate"):
+            params_out = gate(new_params, params)
+            opt_out = adamw.OptState(
+                m=gate(new_opt.m, state.opt.m),
+                v=gate(new_opt.v, state.opt.v),
+                master=gate(new_opt.master, state.opt.master)
+                if state.opt.master is not None else None,
+                step=state.opt.step + committed.astype(jnp.int32),
+            )
 
         out_metrics = {
             "loss": jax.lax.psum(jnp.nan_to_num(loss) * vote, dp_axes) / denom,

@@ -27,6 +27,7 @@ from repro.models import zoo
 from repro.optim import adamw
 from repro.runtime import sharding as shd
 from repro.runtime import spmd
+from repro.runtime import tracing
 from repro.runtime.controlplane import ControlPlane
 
 
@@ -45,7 +46,6 @@ class TrainerConfig:
     keep_last: int = 3
     straggler_ms: float = 1e9      # step-time threshold for reports
     dtype: Any = jnp.float32       # fp32 on CPU test runs; bf16 on TPU
-    log_every: int = 10
 
 
 class Trainer:
@@ -114,28 +114,37 @@ class Trainer:
 
     def train(self) -> List[Dict[str, float]]:
         cfg = self.cfg
-        start_step, state = self.restore_or_init()
+        with tracing.span("train.init"):
+            start_step, state = self.restore_or_init()
         data = SyntheticLM(self.data_cfg, shard_id=0, n_shards=1,
                            start_step=start_step)
         it = Prefetcher(data, depth=2)
         logs: List[Dict[str, float]] = []
         with self.mesh:
             for i in range(start_step, cfg.steps):
-                t0 = time.perf_counter()
-                raw = next(it)
-                batch = self.place_batch(raw)
-                state, metrics = self.step_fn(state, batch)
-                m = {k: float(v) for k, v in metrics.items()}
-                m["wall_ms"] = (time.perf_counter() - t0) * 1e3
-                m["data_step"] = i
-                logs.append(m)
-                if self.control is not None and m["wall_ms"] > cfg.straggler_ms:
-                    self.control.report_straggler(self.host_id, i)
-                if self.ckpt and cfg.ckpt_every and (i + 1) % cfg.ckpt_every == 0:
-                    self.ckpt.save(i + 1, {"state": state})
+                with tracing.step_span(i):
+                    t0 = time.perf_counter()
+                    with tracing.span("train.data"):
+                        raw = next(it)
+                    with tracing.span("train.place"):
+                        batch = self.place_batch(raw)
+                    with tracing.span("train.dispatch"):
+                        state, metrics = self.step_fn(state, batch)
+                    with tracing.span("train.sync"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                    m["wall_ms"] = (time.perf_counter() - t0) * 1e3
+                    m["data_step"] = i
+                    logs.append(m)
+                    if self.control is not None and m["wall_ms"] > cfg.straggler_ms:
+                        with tracing.span("train.straggler"):
+                            self.control.report_straggler(self.host_id, i)
+                    if self.ckpt and cfg.ckpt_every and (i + 1) % cfg.ckpt_every == 0:
+                        with tracing.span("train.ckpt", step=i + 1):
+                            self.ckpt.save(i + 1, {"state": state})
             if self.ckpt:
-                self.ckpt.save(cfg.steps, {"state": state}, async_=False)
-                self.ckpt.wait()
+                with tracing.span("train.ckpt", step=cfg.steps):
+                    self.ckpt.save(cfg.steps, {"state": state}, async_=False)
+                    self.ckpt.wait()
         self.state = state
         return logs
 
