@@ -4,7 +4,8 @@ and dense FFNs. Pure functions over parameter pytrees; no framework.
 Conventions:
   x:      (B, T, d_model) activations, compute dtype bf16 by default
   params: nested dicts of jnp arrays
-  cache:  {"k": (B, S, Hkv, Dh), "v": (B, S, Hkv, Dh)} per attention layer
+  cache:  {"k": (B, S, Hkv, Dh), "v": (B, S, Hkv, Dh)} per attention layer,
+          stacked over the layer groups as (G, B, S, Hkv, Dh)
 Softmax/norm statistics are computed in fp32 regardless of compute dtype.
 """
 from __future__ import annotations
@@ -159,16 +160,26 @@ def _sdpa(q, k, v, *, causal: bool, q_offset: int | jax.Array = 0,
 
 def _chunked_attention(
     q, k, v, *, causal: bool, q_offset=None, kv_len=None,
-    blk_q: int = 512, blk_k: int = 1024,
+    blk_q: int = 512, blk_k: int = 1024, layer=None,
 ) -> jax.Array:
     """Flash-style attention in pure jnp: double lax.scan with online
     softmax, fp32 accumulators, O(blk_q * blk_k) live scores. This is the
     memory- and FLOP-shape the Pallas kernel has on TPU, expressed portably —
     the dry-run lowers this, so compile-time memory analysis reflects the
     production tiling. Wrapped in remat(nothing_saveable): the backward
-    recomputes tiles exactly like the flash backward kernel."""
+    recomputes tiles exactly like the flash backward kernel.
+
+    With ``layer``, k and v are stacked caches (G, B, Tk, Hkv, D) and each
+    key block is sliced from group ``layer`` of the stack inside the loop,
+    so no copy of the layer's cache is made.
+
+    With one query a row (decode), the (b, h)-batched products would have
+    XLA lay the whole cache out anew for them, a copy in and one out on
+    every step. So each key row (position, kv head) is read as stored and
+    multiplied with every query head, and a score row keeps its own head's
+    entries: Hkv times the FLOPs, which decode hardly has."""
     B, Tq, Hq, D = q.shape
-    Tk, Hkv = k.shape[1], k.shape[2]
+    Tk, Hkv = k.shape[-3], k.shape[-2]
     G = Hq // Hkv
     bq = min(blk_q, Tq)
     bk = min(blk_k, Tk)
@@ -180,18 +191,61 @@ def _chunked_attention(
     q_offset = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
 
     qf = (q.astype(jnp.float32) * scale).reshape(B, nq, bq, Hkv, G, D)
-    kf = k.astype(jnp.float32).reshape(B, nk, bk, Hkv, D)
-    vf = v.astype(jnp.float32).reshape(B, nk, bk, Hkv, D)
+    if layer is None:
+        kf = k.astype(jnp.float32).reshape(B, nk, bk, Hkv, D)
+        vf = v.astype(jnp.float32).reshape(B, nk, bk, Hkv, D)
+
+        def key_blocks():
+            return jnp.arange(nk), kf.swapaxes(0, 1), vf.swapaxes(0, 1)
+
+        def key_block(args):
+            return args
+    else:
+        def key_blocks():
+            return jnp.arange(nk)
+
+        def key_block(ki):
+            def blk(c):
+                at = (layer, 0, ki * bk, 0, 0)
+                return jax.lax.dynamic_slice(c, at, (1, B, bk, Hkv, D))[0].astype(jnp.float32)
+            return ki, blk(k), blk(v)
+
+    if bq == 1:
+        # Scores (B, Hkv, G, 1, bk * Hkv): column n is key row (n // Hkv, n % Hkv).
+        def key_pos(ki):
+            return ki * bk + jnp.arange(bk * Hkv) // Hkv
+
+        own = jnp.arange(Hkv)[:, None] == jnp.arange(bk * Hkv)[None, :] % Hkv
+        own = own[None, :, None, None, :]
+
+        def scores(q_blk, k_blk):
+            s = jnp.einsum("bcd,bnd->bcn", q_blk.reshape(B, Hkv * G, D),
+                           k_blk.reshape(B, bk * Hkv, D))
+            return jnp.where(own, s.reshape(B, Hkv, G, 1, bk * Hkv), -1e30)
+
+        def mix(p, v_blk):
+            pv = jnp.einsum("bcn,bnd->bcd", p.reshape(B, Hkv * G, bk * Hkv),
+                            v_blk.reshape(B, bk * Hkv, D))
+            return pv.reshape(B, Hkv, G, 1, D)
+    else:
+        def key_pos(ki):
+            return ki * bk + jnp.arange(bk)
+
+        def scores(q_blk, k_blk):
+            return jnp.einsum("bqhgd,bkhd->bhgqk", q_blk, k_blk)
+
+        def mix(p, v_blk):
+            return jnp.einsum("bhgqk,bkhd->bhgqd", p, v_blk)
 
     def q_chunk(qi, q_blk):
         # q_blk: (B, bq, Hkv, G, D)
         qpos = q_offset[:, None] + qi * bq + jnp.arange(bq)[None, :]  # (B,bq)
 
         def k_chunk(carry, args):
-            ki, k_blk, v_blk = args
+            ki, k_blk, v_blk = key_block(args)
             m, l, acc = carry
-            s = jnp.einsum("bqhgd,bkhd->bhgqk", q_blk, k_blk)
-            kpos = ki * bk + jnp.arange(bk)  # (bk,)
+            s = scores(q_blk, k_blk)
+            kpos = key_pos(ki)  # the key position of each score column
             neg = jnp.asarray(-1e30, jnp.float32)
             if causal:
                 msk = qpos[:, :, None] >= kpos[None, None, :]  # (B,bq,bk)
@@ -203,16 +257,13 @@ def _chunked_attention(
             p = jnp.exp(s - m_new[..., None])
             alpha = jnp.exp(m - m_new)
             l_new = alpha * l + jnp.sum(p, axis=-1)
-            acc_new = acc * alpha[..., None] + jnp.einsum(
-                "bhgqk,bkhd->bhgqd", p, v_blk
-            )
+            acc_new = acc * alpha[..., None] + mix(p, v_blk)
             return (m_new, l_new, acc_new), None
 
         m0 = jnp.full((B, Hkv, G, bq), -1e30, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, bq), jnp.float32)
         a0 = jnp.zeros((B, Hkv, G, bq, D), jnp.float32)
-        ks = (jnp.arange(nk), kf.swapaxes(0, 1), vf.swapaxes(0, 1))
-        (m, l, acc), _ = jax.lax.scan(k_chunk, (m0, l0, a0), ks)
+        (m, l, acc), _ = jax.lax.scan(k_chunk, (m0, l0, a0), key_blocks())
         out = acc / jnp.maximum(l, 1e-30)[..., None]          # (B,Hkv,G,bq,D)
         return out.transpose(0, 3, 1, 2, 4)                   # (B,bq,Hkv,G,D)
 
@@ -239,7 +290,12 @@ def _chunked_remat(causal: bool, has_kvlen: bool, blk_q: int, blk_k: int):
 
 
 def chunked_attention(q, k, v, *, causal, q_offset=None, kv_len=None,
-                      blk_q=512, blk_k=1024):
+                      blk_q=512, blk_k=1024, layer=None):
+    """Tiled attention; with ``layer``, over group ``layer`` of the stacked
+    caches k, v (a cached step: nothing to differentiate, so no remat)."""
+    if layer is not None:
+        return _chunked_attention(q, k, v, causal=causal, q_offset=q_offset,
+                                  kv_len=kv_len, blk_q=blk_q, blk_k=blk_k, layer=layer)
     B = q.shape[0]
     qo = (jnp.zeros((B,), jnp.int32) if q_offset is None
           else jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,)))
@@ -252,6 +308,18 @@ def chunked_attention(q, k, v, *, causal, q_offset=None, kv_len=None,
 CHUNKED_ATTN_THRESHOLD = 1024  # use tiled path at/above this many kv tokens
 
 
+def _write_tokens(stack: jax.Array, new: jax.Array, layer: jax.Array,
+                  cache_pos: jax.Array) -> jax.Array:
+    """Write the T new rows ``new`` (B, T, Hkv, Dh) into layer ``layer`` of
+    the stacked cache (G, B, S, Hkv, Dh) at slot ``cache_pos`` (scalar) or
+    ``cache_pos[b]`` (per row), in place."""
+    if jnp.ndim(cache_pos) == 0:
+        return jax.lax.dynamic_update_slice(stack, new[None], (layer, 0, cache_pos, 0, 0))
+    B, T = new.shape[:2]
+    slots = cache_pos[:, None] + jnp.arange(T)[None, :]
+    return stack.at[layer, jnp.arange(B)[:, None], slots].set(new)
+
+
 @scope("attention")
 def attention(
     cfg: ArchConfig,
@@ -261,11 +329,16 @@ def attention(
     positions: Optional[jax.Array] = None,
     cache: Optional[Params] = None,
     cache_pos: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     learned_pos_table: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Params]]:
-    """Full attention: training/prefill when cache is None, one-step decode
-    when cache is given (x has T=1; cache_pos is the write index (B,) or
-    scalar)."""
+    """Full attention: training when cache is None; else a cached step of T
+    tokens (prefill or decode) at slot cache_pos ((B,) or scalar).
+
+    The cache is the whole stack, {"k", "v"}: (G, B, S, Hkv, Dh), carried
+    through the layer scan. The step writes only its T new K/V rows into
+    layer ``layer`` of it, in place (scope ``kv_update``), then attends over
+    that layer's slice of the updated stack, and returns the stack."""
     B, T, _ = x.shape
     if positions is None:
         if cache is None:
@@ -288,24 +361,22 @@ def attention(
             out = _sdpa(q, k, v, causal=True)
         new_cache = None
     else:
-        idx = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1), (B,))
+        cache_pos = jnp.asarray(cache_pos, jnp.int32)
+        idx = jnp.broadcast_to(cache_pos.reshape(-1), (B,))
         with scope("kv_update"):
-            k_cache = jax.vmap(lambda c, kn, i: jax.lax.dynamic_update_slice(c, kn, (i, 0, 0)))(
-                cache["k"], k, idx
-            )
-            v_cache = jax.vmap(lambda c, vn, i: jax.lax.dynamic_update_slice(c, vn, (i, 0, 0)))(
-                cache["v"], v, idx
-            )
+            new_cache = {"k": _write_tokens(cache["k"], k, layer, cache_pos),
+                         "v": _write_tokens(cache["v"], v, layer, cache_pos)}
         # Causal over the cache: query t (global position idx+t) sees keys
         # [0, idx+t]; kv_len hides never-written slots.
-        if k_cache.shape[1] >= CHUNKED_ATTN_THRESHOLD:
+        if new_cache["k"].shape[2] >= CHUNKED_ATTN_THRESHOLD:
             out = chunked_attention(
-                q, k_cache, v_cache, causal=True, q_offset=idx, kv_len=idx + T,
-                blk_q=min(512, T), blk_k=1024,
+                q, new_cache["k"], new_cache["v"], causal=True, q_offset=idx,
+                kv_len=idx + T, blk_q=min(512, T), blk_k=1024, layer=layer,
             )
         else:
+            k_cache, v_cache = (jax.lax.dynamic_index_in_dim(new_cache[n], layer, keepdims=False)
+                                for n in ("k", "v"))
             out = _sdpa(q, k_cache, v_cache, causal=True, q_offset=idx, kv_len=idx + T)
-        new_cache = {"k": k_cache, "v": v_cache}
 
     y = out.reshape(B, T, cfg.q_dim) @ p["wo"]
     return y, new_cache

@@ -67,6 +67,9 @@ def init_block(cfg: ArchConfig, kind: str, is_moe: bool, rng, dtype) -> Params:
     return p
 
 
+_RECURRENT = {"mamba": S.apply_mamba, "mlstm": S.apply_mlstm, "slstm": S.apply_slstm}
+
+
 def apply_block(
     cfg: ArchConfig,
     kind: str,
@@ -77,20 +80,27 @@ def apply_block(
     positions: Optional[jax.Array],
     cache: Optional[Params],
     cache_pos: Optional[jax.Array],
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[Params]]:
+    """One block. ``cache``, when given, is this block's cache stacked over
+    the layer groups; the block updates group ``layer`` of it and returns the
+    stack: attention writes only its new tokens' K/V, a recurrent block its
+    whole (small) state."""
     aux = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
     h = L.apply_norm(cfg, p["norm1"], x)
     new_cache = cache
     if kind == "attn":
         y, new_cache = L.attention(
-            cfg, p["mixer"], h, positions=positions, cache=cache, cache_pos=cache_pos
+            cfg, p["mixer"], h, positions=positions, cache=cache, cache_pos=cache_pos,
+            layer=layer,
         )
-    elif kind == "mamba":
-        y, new_cache = S.apply_mamba(cfg, p["mixer"], h, state=cache)
-    elif kind == "mlstm":
-        y, new_cache = S.apply_mlstm(cfg, p["mixer"], h, state=cache)
-    elif kind == "slstm":
-        y, new_cache = S.apply_slstm(cfg, p["mixer"], h, state=cache)
+    elif kind in _RECURRENT:
+        state = None if cache is None else jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False), cache)
+        y, state = _RECURRENT[kind](cfg, p["mixer"], h, state=state)
+        if cache is not None:
+            new_cache = jax.tree.map(
+                lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, layer, 0), cache, state)
     else:
         raise ValueError(kind)
     x = x + y
@@ -157,31 +167,49 @@ def apply_stack(
     train: bool = False,
     gather_fn=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[Params]]:
-    """gather_fn (optional): FSDP weight streaming — applied to each group's
+    """Scan the layer groups over ``stack_params``.
+
+    caches (optional): the stacked per-group caches (``init_stack_cache``).
+    They ride in the scan's carry, not its xs/ys, so each block updates its
+    group's part of the one stacked buffer in place and no step moves a
+    whole cache; the updated stack is returned as ``new_caches``.
+
+    gather_fn (optional): FSDP weight streaming — applied to each group's
     parameter subtree INSIDE the scan body, so only one layer-group of full
     weights is live at a time (ZeRO-3). Its autodiff transpose produces the
     per-group reduce-scatter of gradients for free."""
     sig = period_signature(cfg)
 
-    def group_body(carry, xs):
-        x, aux = carry
-        if caches is None:
-            gp = xs
-            gc = {f"b{j}": None for j in range(len(sig))}
-        else:
-            gp, gc = xs
+    def group(gp, x, aux, gc, layer):
         if gather_fn is not None:
             gp = gather_fn(gp)
-        new_gc = {}
         for j, (kind, is_moe) in enumerate(sig):
             x, a, c = apply_block(
                 cfg, kind, is_moe, gp[f"b{j}"], x,
-                positions=positions, cache=gc[f"b{j}"], cache_pos=cache_pos,
+                positions=positions, cache=None if gc is None else gc[f"b{j}"],
+                cache_pos=cache_pos, layer=layer,
             )
             aux = {k: aux[k] + a[k] for k in AUX_KEYS}
-            new_gc[f"b{j}"] = c
-        out = new_gc if caches is not None else None
-        return (x, aux), out
+            if gc is not None:
+                gc = {**gc, f"b{j}": c}
+        return x, aux, gc
+
+    aux0 = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
+    if caches is not None:
+        def cached_body(carry, xs):
+            x, aux, gc = carry
+            layer, gp = xs
+            return group(gp, x, aux, gc, layer), None
+
+        G = n_groups(cfg)
+        (x, aux, new_caches), _ = jax.lax.scan(
+            cached_body, (x, aux0, caches), (jnp.arange(G), stack_params))
+        return x, aux, new_caches
+
+    def group_body(carry, gp):
+        x, aux = carry
+        x, aux, _ = group(gp, x, aux, None, None)
+        return (x, aux), None
 
     if train and cfg.remat != "none":
         policy = (
@@ -191,7 +219,5 @@ def apply_stack(
         )
         group_body = jax.checkpoint(group_body, policy=policy, prevent_cse=False)
 
-    aux0 = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
-    xs = stack_params if caches is None else (stack_params, caches)
-    (x, aux), new_caches = jax.lax.scan(group_body, (x, aux0), xs)
-    return x, aux, new_caches
+    (x, aux), _ = jax.lax.scan(group_body, (x, aux0), stack_params)
+    return x, aux, None

@@ -126,6 +126,7 @@ def test_rmsnorm(shape, dtype):
     [
         (2, 2048, 2048, 4, 2, 32, None),         # training shape
         (2, 1, 2048, 4, 4, 32, (1000, 1500)),    # decode against cache
+        (2, 1, 2048, 8, 2, 32, (1000, 1500)),    # decode, 4 query heads a kv head
         (1, 1024, 2048, 4, 2, 32, (512,)),       # chunked prefill w/ offset
     ],
 )
@@ -144,6 +145,24 @@ def test_chunked_attention_matches_sdpa(B, Tq, Tk, Hq, Hkv, D, offset):
                    q_offset=q_offset if q_offset is not None else 0,
                    kv_len=kv_len)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5, rtol=1e-2)
+
+
+@pytest.mark.parametrize("Tq", [1, 256])
+def test_chunked_attention_reads_one_layer_of_a_stack(Tq):
+    """With ``layer``, the tiled path reads its blocks straight from the
+    stacked caches and gives what it gives on that layer's own cache."""
+    from repro.models import layers as L
+
+    G_, B, Tk, Hq, Hkv, D = 3, 2, 2048, 8, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    q = jax.random.normal(ks[0], (B, Tq, Hq, D), jnp.float32)
+    k = jax.random.normal(ks[1], (G_, B, Tk, Hkv, D), jnp.float32)
+    v = jax.random.normal(ks[2], (G_, B, Tk, Hkv, D), jnp.float32)
+    q_offset = jnp.asarray([700, 1500], jnp.int32)
+    kw = dict(causal=True, q_offset=q_offset, kv_len=q_offset + Tq, blk_q=256, blk_k=512)
+    out = L.chunked_attention(q, k, v, layer=jnp.int32(1), **kw)
+    want = L.chunked_attention(q, k[1], v[1], **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5, rtol=1e-4)
 
 
 def test_chunked_attention_grads_match():

@@ -131,3 +131,32 @@ def test_moe_active_params_smaller_than_total():
     # llama4-scout: ~17B active.
     a = registry.get("llama4-scout-17b-a16e").active_param_count()
     assert 10e9 < a < 25e9, a
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "jamba-v0.1-52b"])
+def test_decode_writes_only_its_slot(arch):
+    """A decode step changes the stacked attention cache only at slot pos of
+    every row in every layer, and advances pos by one."""
+    cfg = registry.get(arch, reduced=True)
+    model = zoo.build(cfg, dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(3))
+    B, T, S = 2, 4, 8
+    batch = _batch(cfg, B, T + 3, seed=3)
+    _, cache = jax.jit(lambda p, t: model.prefill(p, {"tokens": t}, S))(
+        params, batch["tokens"][:, :T])
+    step = jax.jit(model.decode_step)
+    attn = [k for k, c in cache["layers"].items() if "k" in c]
+    assert attn
+    for t in range(T, T + 3):
+        pos = int(cache["pos"])
+        assert pos == t
+        _, new = step(params, cache, {"tokens": batch["tokens"][:, t:t + 1]})
+        assert int(new["pos"]) == pos + 1
+        for blk in attn:
+            for n in ("k", "v"):
+                old, cur = np.asarray(cache["layers"][blk][n]), np.asarray(new["layers"][blk][n])
+                assert cur.shape == old.shape and cur.dtype == old.dtype
+                changed = np.any(cur != old, axis=(-2, -1))        # (G, B, S)
+                assert changed[:, :, pos].all(), (blk, n, pos)
+                assert not np.delete(changed, pos, axis=2).any(), (blk, n, pos)
+        cache = new
