@@ -171,7 +171,10 @@ def _chunked_attention(
 
     With ``layer``, k and v are stacked caches (G, B, Tk, Hkv, D) and each
     key block is sliced from group ``layer`` of the stack inside the loop,
-    so no copy of the layer's cache is made.
+    so no copy of the layer's cache is made. The loop (scope ``kv_blocks``)
+    then stops after the last block a query of the chunk can see, by
+    ``kv_len`` and, when causal, the diagonal: every score of a later block
+    is masked, so it would leave m, l and acc bit for bit as they are.
 
     With one query a row (decode), the (b, h)-batched products would have
     XLA lay the whole cache out anew for them, a copy in and one out on
@@ -195,15 +198,9 @@ def _chunked_attention(
         kf = k.astype(jnp.float32).reshape(B, nk, bk, Hkv, D)
         vf = v.astype(jnp.float32).reshape(B, nk, bk, Hkv, D)
 
-        def key_blocks():
-            return jnp.arange(nk), kf.swapaxes(0, 1), vf.swapaxes(0, 1)
-
         def key_block(args):
             return args
     else:
-        def key_blocks():
-            return jnp.arange(nk)
-
         def key_block(ki):
             def blk(c):
                 at = (layer, 0, ki * bk, 0, 0)
@@ -263,7 +260,18 @@ def _chunked_attention(
         m0 = jnp.full((B, Hkv, G, bq), -1e30, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, bq), jnp.float32)
         a0 = jnp.zeros((B, Hkv, G, bq, D), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(k_chunk, (m0, l0, a0), key_blocks())
+        if layer is None:
+            (m, l, acc), _ = jax.lax.scan(
+                k_chunk, (m0, l0, a0), (jnp.arange(nk), kf.swapaxes(0, 1), vf.swapaxes(0, 1)))
+        else:
+            end = jnp.int32(Tk)                  # one past the last key the chunk sees
+            if kv_len is not None:
+                end = jnp.minimum(end, jnp.max(kv_len))
+            if causal:
+                end = jnp.minimum(end, jnp.max(q_offset) + (qi + 1) * bq)
+            with scope("kv_blocks"):
+                m, l, acc = jax.lax.fori_loop(0, (end + bk - 1) // bk,
+                                              lambda ki, c: k_chunk(c, ki)[0], (m0, l0, a0))
         out = acc / jnp.maximum(l, 1e-30)[..., None]          # (B,Hkv,G,bq,D)
         return out.transpose(0, 3, 1, 2, 4)                   # (B,bq,Hkv,G,D)
 

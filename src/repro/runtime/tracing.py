@@ -24,6 +24,10 @@ SPANS = ("train.step", "train.data", "train.place", "train.dispatch", "train.syn
 SCOPES = ("train/fwd_bwd", "train/vote", "train/reduce", "train/clip", "train/adamw",
           "train/gate", "embed", "attention", "kv_update", "ffn", "head")
 
+# Device scopes nested inside one of SCOPES, naming part of its time:
+# ``kv_blocks``, attention's loop over the cache blocks that hold tokens.
+INNER_SCOPES = ("kv_blocks",)
+
 
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
     """Host span ``repro.<name>``, with ``ids`` as its arguments."""
@@ -37,6 +41,6 @@ def step_span(step: int) -> jax.profiler.StepTraceAnnotation:
 
 
 def scope(name: str):
-    """Name scope for device code, ``name`` one of ``SCOPES``."""
-    assert name in SCOPES, name
+    """Name scope for device code, ``name`` one of ``SCOPES`` or ``INNER_SCOPES``."""
+    assert name in SCOPES + INNER_SCOPES, name
     return jax.named_scope(name)

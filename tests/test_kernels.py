@@ -165,6 +165,63 @@ def test_chunked_attention_reads_one_layer_of_a_stack(Tq):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5, rtol=1e-4)
 
 
+# A cached call: (Tq, q_offset a row, blk_q, blk_k). Decode against rows of
+# different fill, and prefill chunks at the start and further on.
+CACHED = {
+    "decode": (1, (700, 1500), 512, 512),
+    "prefill": (512, (0, 0), 256, 512),
+    "prefill-diagonal": (512, (0, 0), 256, 128),
+    "prefill-later": (512, (1024, 1024), 256, 256),
+}
+
+
+def _stacked_cache_call(Tq, offset, Tk=4096, G_=3, layer=1):
+    B, Hq, Hkv, D = 2, 8, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = jax.random.normal(ks[0], (B, Tq, Hq, D), jnp.float32)
+    k = jax.random.normal(ks[1], (G_, B, Tk, Hkv, D), jnp.float32)
+    v = jax.random.normal(ks[2], (G_, B, Tk, Hkv, D), jnp.float32)
+    q_offset = jnp.asarray(offset, jnp.int32)
+    return q, k, v, q_offset, q_offset + Tq, jnp.int32(layer)
+
+
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+def test_chunked_attention_never_reads_a_dead_cache_block(case):
+    """The cached path's loop stops at the live length: every whole key and
+    value block past it, and every other layer of the stack, holds NaN, which
+    the value product (0 * NaN) would carry into the result."""
+    from repro.models import layers as L
+
+    Tq, offset, bq, bk = CACHED[case]
+    q, k, v, q_offset, kv_len, layer = _stacked_cache_call(Tq, offset)
+    live = -(-int(kv_len.max()) // bk) * bk
+    dead = np.zeros(k.shape, bool)
+    dead[:, :, live:] = True
+    dead[np.arange(k.shape[0]) != int(layer)] = True
+    k, v = (jnp.where(dead, jnp.nan, c) for c in (k, v))
+    out = L.chunked_attention(q, k, v, causal=True, q_offset=q_offset, kv_len=kv_len,
+                              blk_q=bq, blk_k=bk, layer=layer)
+    assert np.isfinite(np.asarray(out)).all()
+    n = int(kv_len.max())
+    want = L._sdpa(q, k[layer, :, :n], v[layer, :, :n], causal=True,
+                   q_offset=q_offset, kv_len=kv_len)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5, rtol=1e-2)
+
+
+@pytest.mark.parametrize("case", sorted(CACHED))
+def test_chunked_attention_stops_at_the_live_length_exactly(case):
+    """The bounded loop gives, bit for bit, what the loop over every key
+    block (the path without ``layer``) gives on the layer's own cache."""
+    from repro.models import layers as L
+
+    Tq, offset, bq, bk = CACHED[case]
+    q, k, v, q_offset, kv_len, layer = _stacked_cache_call(Tq, offset)
+    kw = dict(causal=True, q_offset=q_offset, kv_len=kv_len, blk_q=bq, blk_k=bk)
+    out = L.chunked_attention(q, k, v, layer=layer, **kw)
+    want = L.chunked_attention(q, k[layer], v[layer], **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
 def test_chunked_attention_grads_match():
     from repro.models import layers as L
 

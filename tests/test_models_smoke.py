@@ -1,6 +1,8 @@
 """Per-architecture smoke tests on REDUCED configs (assignment requirement):
 instantiate, one forward + train-grad step on CPU, assert output shapes and
 no NaNs; plus prefill/decode-parity for the serving path."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -160,3 +162,34 @@ def test_decode_writes_only_its_slot(arch):
                 assert changed[:, :, pos].all(), (blk, n, pos)
                 assert not np.delete(changed, pos, axis=2).any(), (blk, n, pos)
         cache = new
+
+
+def test_decode_across_a_cache_block_matches_forward():
+    """Through the tiled cached path (2048 slots, blocks of 1024): a 1024-token
+    prefill reads one block, and decode steps at slots 1024 on read the second
+    as it fills, reproducing the parallel forward's logits."""
+    cfg = registry.get("qwen3-1.7b", reduced=True)
+    model = zoo.build(cfg, dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(4))
+    B, S, P = 2, 2048, 1024
+    tokens = _batch(cfg, B, S, seed=4)["tokens"]
+    full_logits, _ = jax.jit(model.forward)(params, {"tokens": tokens})
+    logits, cache = jax.jit(lambda p, t: model.prefill(p, {"tokens": t}, S))(params, tokens[:, :P])
+    step = jax.jit(model.decode_step)
+    for t in range(P, P + 3):
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(full_logits[:, t - 1]),
+                                   rtol=2e-4, atol=2e-4)
+        logits, cache = step(params, cache, {"tokens": tokens[:, t:t + 1]})
+
+
+def test_decode_step_bounds_its_cache_loop_in_the_kv_blocks_scope():
+    """The compiled decode step's loop over cache blocks carries the
+    ``kv_blocks`` scope, nested under ``attention``."""
+    cfg = registry.get("qwen3-1.7b", reduced=True)
+    model = zoo.build(cfg, dtype=jnp.float32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(2, 2048))
+    tok = {"tokens": jax.ShapeDtypeStruct((2, 1), jnp.int32)}
+    text = jax.jit(model.decode_step).lower(params, cache, tok).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(re.search(r"(^|/)attention/(.*/)?kv_blocks/", n) for n in names)
