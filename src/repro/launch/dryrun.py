@@ -180,8 +180,7 @@ def parse_collectives(hlo_text: str, n_devices: int) -> Dict[str, Any]:
 # --------------------------------------------------------------- cell runner
 
 
-def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str,
-               fsdp_stream: bool = True) -> Dict[str, Any]:
+def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str) -> Dict[str, Any]:
     cfg = registry.get(arch)
     shape = SHAPES[shape_name]
     model = zoo.build(cfg, dtype=jnp.bfloat16)
@@ -191,13 +190,12 @@ def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str,
         opt_cfg = AdamWConfig()
         step_fn, state_shardings, _ = spmd.build_train_step(
             model, opt_cfg, mesh, track="fast", donate=True,
-            fsdp_stream=fsdp_stream,
         )
         state_tpl = jax.eval_shape(
-            lambda rng: spmd.make_train_state(model, opt_cfg, rng, False),
+            lambda rng: spmd.make_train_state(model, opt_cfg, rng),
             jax.random.PRNGKey(0),
         )
-        specs = spmd.state_specs(model, opt_cfg, mesh, False)
+        specs = spmd.state_specs(model, opt_cfg, mesh)
         state_structs = _shaped(state_tpl, mesh, specs)
         batch = input_specs(arch, shape_name, mesh)
         lowered = step_fn.lower(state_structs, batch)
@@ -270,8 +268,7 @@ def lower_cell(arch: str, shape_name: str, mesh, mesh_name: str,
 
 
 def run_cell(arch: str, shape_name: str, mesh_name: str,
-             force: bool = False, fsdp_stream: bool = True,
-             artifact_dir: Optional[str] = None) -> Optional[Dict[str, Any]]:
+             force: bool = False, artifact_dir: Optional[str] = None) -> Optional[Dict[str, Any]]:
     cfg = registry.get(arch)
     shape = SHAPES[shape_name]
     outdir = os.path.join(artifact_dir or ARTIFACT_DIR, mesh_name)
@@ -292,8 +289,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
     mesh = make_production_mesh(multi_pod=(mesh_name == "multi"))
     print(f"[dryrun] {mesh_name}/{arch}/{shape_name}: lowering...", flush=True)
     try:
-        result = lower_cell(arch, shape_name, mesh, mesh_name,
-                            fsdp_stream=fsdp_stream)
+        result = lower_cell(arch, shape_name, mesh, mesh_name)
         print(
             f"[dryrun] {mesh_name}/{arch}/{shape_name}: OK "
             f"compile={result['timings_s']['compile']:.1f}s "
@@ -320,8 +316,6 @@ def main() -> int:
     ap.add_argument("--mesh", choices=["single", "multi", "both"], default="single")
     ap.add_argument("--all", action="store_true", help="every (arch x shape)")
     ap.add_argument("--force", action="store_true")
-    ap.add_argument("--no-stream", action="store_true",
-                    help="fsdp_stream=False baseline (whole-tree gather)")
     ap.add_argument("--out", default=None, help="artifact dir override")
     args = ap.parse_args()
 
@@ -339,7 +333,7 @@ def main() -> int:
     for mesh_name in meshes:
         for arch, shape_name in cells:
             r = run_cell(arch, shape_name, mesh_name, force=args.force,
-                         fsdp_stream=not args.no_stream, artifact_dir=args.out)
+                         artifact_dir=args.out)
             if r and "error" in r:
                 failures += 1
     print(f"[dryrun] done; failures={failures}")

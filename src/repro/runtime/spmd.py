@@ -5,7 +5,8 @@ x AUTO over "model" (TP/EP stays with the SPMD partitioner). Manual DP is
 what makes the paper's technique first-class in-graph:
 
   1. FSDP gather:   per-leaf ``all_gather`` over "data" on the leaf's FSDP
-                    dim (just-in-time weights; ZeRO-3).
+                    dim (just-in-time weights; ZeRO-3), inside the stack
+                    scan for the layer groups.
   2. local grad:    each DP replica differentiates its OWN microbatch loss —
                     per-replica gradients exist as real values, not just as
                     HLO internals.
@@ -20,21 +21,18 @@ what makes the paper's technique first-class in-graph:
                     the tentative-slot semantics of the paper, in XLA.
   5. sharded AdamW: optimizer state lives and updates in FSDP+TP shards.
 
-Cross-pod gradient reduction can optionally ride int8 + error feedback
-(compress_pod=True) — the DCN hop is the narrow one.
+The barrier's pieces live in ``core/collective.py`` (DESIGN.md §14).
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.collective import classic_track_commit, fast_quorum_size
-from repro.optim import adamw, compression
+from repro.core import collective
+from repro.optim import adamw
 from repro.runtime import sharding as shd
 from repro.runtime.tracing import scope
 
@@ -44,7 +42,6 @@ Params = Any
 class TrainState(NamedTuple):
     params: Params
     opt: adamw.OptState
-    ef_residual: Optional[Params]  # error-feedback (compress_pod only)
 
 
 def _dp_size(mesh: Mesh) -> int:
@@ -65,24 +62,18 @@ def _gather_params(params, specs):
                                   is_leaf=lambda x: isinstance(x, P))
 
 
-def make_train_state(model, opt_cfg: adamw.AdamWConfig, rng,
-                     compress_pod: bool = False) -> TrainState:
+def make_train_state(model, opt_cfg: adamw.AdamWConfig, rng) -> TrainState:
     params = model.init(rng)
-    opt = adamw.init(opt_cfg, params)
-    ef = compression.init_residual(params) if compress_pod else None
-    return TrainState(params, opt, ef)
+    return TrainState(params, adamw.init(opt_cfg, params))
 
 
-def state_specs(model, opt_cfg: adamw.AdamWConfig, mesh: Mesh,
-                compress_pod: bool = False):
+def state_specs(model, opt_cfg: adamw.AdamWConfig, mesh: Mesh):
     p_tpl = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     p_specs = shd.tree_param_specs(p_tpl, mesh)
-    m_specs = p_specs
     master_specs = p_specs if opt_cfg.master_weights else None
-    opt_specs = adamw.OptState(m=m_specs, v=m_specs, master=master_specs,
+    opt_specs = adamw.OptState(m=p_specs, v=p_specs, master=master_specs,
                                step=P())
-    ef_specs = p_specs if compress_pod else None
-    return TrainState(p_specs, opt_specs, ef_specs)
+    return TrainState(p_specs, opt_specs)
 
 
 def build_train_step(
@@ -90,32 +81,25 @@ def build_train_step(
     opt_cfg: adamw.AdamWConfig,
     mesh: Mesh,
     track: str = "fast",
-    compress_pod: bool = False,
-    vote_max_norm: float = 1e4,
     donate: bool = True,
-    fsdp_stream: bool = True,
 ) -> Tuple[Callable, TrainState, Any]:
     """Returns (jitted step_fn, state_shardings, batch_sharding_fn).
 
     step_fn: (TrainState, batch) -> (TrainState, metrics)
 
-    fsdp_stream=True (default): layer-group weights are all-gathered INSIDE
-    the stack scan (ZeRO-3 streaming — one group of full weights live at a
-    time; gradient reduce-scatter per group comes from the gather's autodiff
-    transpose). False = gather the whole tree upfront (the naive baseline
-    kept for the §Perf comparison; does not fit HBM for the largest archs).
+    Layer-group weights are all-gathered INSIDE the stack scan (ZeRO-3
+    streaming: one group of full weights live at a time); the gather's
+    autodiff transpose hands their gradients back reduce-scattered.
 
-    Consensus gating granularity (see DESIGN.md): per-replica exclusion via
+    Consensus gating granularity (DESIGN.md §14): per-replica exclusion via
     the fast vote applies to pre-reduction quantities (loss and the
     non-streamed leaves); streamed-stack gradients are reduced inside
     autodiff, so a poisoned replica there is caught by the global finiteness
-    check -> the step rolls back (tentative-slot semantics) and repeated
-    rollbacks escalate to control-plane exclusion of the host.
+    check and the step rolls back (tentative-slot semantics).
     """
     dp_axes = shd.batch_axes(mesh)
-    M = _dp_size(mesh)
-    fq = fast_quorum_size(M)
-    specs = state_specs(model, opt_cfg, mesh, compress_pod)
+    fq = collective.fast_quorum_size(_dp_size(mesh))
+    specs = state_specs(model, opt_cfg, mesh)
     p_specs = specs.params
 
     def make_gather_fn(stack_specs):
@@ -124,7 +108,7 @@ def build_train_step(
         shifts down by one. After the gather the TP placement is re-PINNED
         with an explicit constraint — without it the SPMD partitioner loses
         the model-axis sharding of scan-carried weights and replicates them
-        (12x FLOPs + per-group weight gathers; see EXPERIMENTS.md §Perf)."""
+        (12x FLOPs + per-group weight gathers)."""
 
         def gather_group(gp):
             def one(p, spec):
@@ -147,156 +131,99 @@ def build_train_step(
         params = state.params
 
         with scope("train/fwd_bwd"):
-            if fsdp_stream:
-                rest = {k: v for k, v in params.items() if k != "stack"}
-                rest_specs = {k: p_specs[k] for k in rest}
-                rest_full = _gather_params(rest, rest_specs)
-                gather_fn = make_gather_fn(p_specs["stack"])
+            rest = {k: v for k, v in params.items() if k != "stack"}
+            rest_specs = {k: p_specs[k] for k in rest}
+            rest_full = _gather_params(rest, rest_specs)
+            gather_fn = make_gather_fn(p_specs["stack"])
 
-                def loss_fn(diff):
-                    rf, local_stack = diff
-                    p = dict(rf)
-                    p["stack"] = local_stack
-                    return model.loss(p, batch, gather_fn=gather_fn)
+            def loss_fn(diff):
+                rf, local_stack = diff
+                p = dict(rf)
+                p["stack"] = local_stack
+                return model.loss(p, batch, gather_fn=gather_fn)
 
-                (loss, metrics), (g_rest, g_stack) = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )((rest_full, params["stack"]))
-                # g_stack is ALREADY reduce-scattered+summed over "data" (gather
-                # transpose); g_rest is per-replica and full-shaped.
-                grads = dict(g_rest)
-                grads["stack"] = g_stack
-                prereduction = {k: g_rest[k] for k in g_rest}
-            else:
-                full_params = _gather_params(params, p_specs)
-
-                def loss_fn(fp):
-                    return model.loss(fp, batch)
-
-                (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    full_params
-                )
-                prereduction = grads
+            (loss, metrics), (g_rest, g_stack) = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )((rest_full, params["stack"]))
+            # g_stack is ALREADY reduce-scattered+summed over "data" (gather
+            # transpose); g_rest is per-replica and full-shaped.
+            grads = dict(g_rest)
+            grads["stack"] = g_stack
 
         # --- Fast Raft vote: this replica's local signals.
         with scope("train/vote"):
-            finite = jnp.isfinite(loss)
-            sq = jnp.asarray(0.0, jnp.float32)
-            for g in jax.tree_util.tree_leaves(prereduction):
-                finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(g)))
-                sq = sq + jnp.sum(jnp.square(g.astype(jnp.float32)))
-            vote = jnp.logical_and(finite, jnp.sqrt(sq) < vote_max_norm).astype(jnp.float32)
-
+            vote = collective.replica_vote(loss, jax.tree_util.tree_leaves(g_rest))
             if track == "classic":
                 # Baseline: two dedicated vote rounds before the reduction.
-                n_yes, committed = classic_track_commit(vote, dp_axes)
-                # classic commits on majority; hold it to the same fast quorum
-                # for an apples-to-apples gate.
-                committed = n_yes >= jnp.asarray(fq, n_yes.dtype)
+                n_yes, committed = collective.classic_track_commit(vote, dp_axes, fq)
 
         # --- Gradient reduction phase.
-        flat, treedef = jax.tree_util.tree_flatten_with_path(grads)
-        spec_flat = [
-            shd.param_spec(shd.path_str(path), g.shape, mesh) for path, g in flat
-        ]
-
-        def already_reduced(path, spec) -> bool:
-            # Streamed-stack FSDP leaves: the all_gather transpose already
-            # reduce-scattered them over "data". Stack leaves WITHOUT an FSDP
-            # dim (norm scales, gate biases) stay per-replica and join the
-            # fused psum like any other plain leaf.
-            return (
-                fsdp_stream
-                and shd.path_str(path).startswith("stack")
-                and shd.fsdp_dim(spec) is not None
-            )
+        flat_p, treedef = jax.tree_util.tree_flatten_with_path(grads)
+        paths = [shd.path_str(path) for path, _ in flat_p]
+        flat = [g for _, g in flat_p]
+        spec_flat = [shd.param_spec(path, g.shape, mesh) for path, g in zip(paths, flat)]
+        fsdp = [shd.fsdp_dim(s) for s in spec_flat]
+        # Streamed-stack FSDP leaves: the all_gather transpose already
+        # reduce-scattered them over "data". Stack leaves WITHOUT an FSDP
+        # dim (norm scales, gate biases) stay per-replica and join the
+        # fused psum like any other plain leaf.
+        streamed = [path.startswith("stack") and d is not None
+                    for path, d in zip(paths, fsdp)]
 
         # Per-replica Fast Raft gate on every PRE-reduction leaf: a replica
         # that voted 0 contributes exactly nothing to the committed update.
         with scope("train/vote"):
-            flat = [
-                (path, g if already_reduced(path, s)
-                 else (jnp.nan_to_num(g.astype(jnp.float32)) * vote).astype(g.dtype))
-                for (path, g), s in zip(flat, spec_flat)
-            ]
+            flat = [g if st else collective.gate_contribution(g, vote)
+                    for g, st in zip(flat, streamed)]
 
         with scope("train/reduce"):
-            fsdp_items = [(i, shd.fsdp_dim(s)) for i, s in enumerate(spec_flat)]
-            reduced: list = [None] * len(flat)
-
-            # Non-FSDP, per-replica leaves + the vote ride ONE fused psum (the
-            # fast track).
-            plain_idx = [i for i, d in fsdp_items if d is None]
-            plain = tuple(flat[i][1] for i in plain_idx)
+            # Non-FSDP, per-replica leaves (+ the vote on the fast track)
+            # ride ONE psum.
+            plain_idx = [i for i, d in enumerate(fsdp) if d is None]
+            plain = [flat[i] for i in plain_idx]
             if track == "fast":
-                out = jax.lax.psum((*plain, vote), dp_axes)
-                *plain_out, n_yes = out
-                committed = n_yes >= jnp.asarray(fq, n_yes.dtype)
+                plain_out, n_yes, committed = collective.fast_track_psum(
+                    plain, vote, dp_axes, fq)
             else:
-                plain_out = list(jax.lax.psum(plain, dp_axes)) if plain else []
+                plain_out = jax.lax.psum(tuple(plain), dp_axes) if plain else ()
+            reduced = list(flat)
             for i, g in zip(plain_idx, plain_out):
                 reduced[i] = g
 
             # FSDP leaves: reduce_scatter over "data" (unless the streaming
-            # gather transpose already did it), then the cross-pod hop
-            # (optionally int8 + error feedback on the DCN link).
-            ef_leaves = (
-                jax.tree_util.tree_flatten_with_path(state.ef_residual)[0]
-                if state.ef_residual is not None else None
-            )
-            new_ef_flat: Dict[int, jax.Array] = {}
-            for i, d in fsdp_items:
-                path, g = flat[i]
+            # gather transpose already did it), then the cross-pod hop.
+            for i, d in enumerate(fsdp):
                 if d is None:
-                    continue  # handled in the fused psum above
-                pre_done = already_reduced(path, spec_flat[i])
-                if (not pre_done) and "data" in dp_axes and mesh.shape["data"] > 1:
+                    continue
+                g = reduced[i]
+                if not streamed[i] and "data" in dp_axes and mesh.shape["data"] > 1:
                     g = jax.lax.psum_scatter(g, "data", scatter_dimension=d, tiled=True)
                 if "pod" in dp_axes:
-                    if compress_pod and ef_leaves is not None:
-                        gf = g.astype(jnp.float32) + ef_leaves[i][1]
-                        scale = jnp.maximum(jnp.max(jnp.abs(gf)), 1e-12) / 127.0
-                        q = jnp.clip(jnp.round(gf / scale), -127, 127).astype(jnp.int8)
-                        new_ef_flat[i] = gf - q.astype(jnp.float32) * scale
-                        qs = jax.lax.all_gather(q, "pod")          # int8 on DCN
-                        ss = jax.lax.all_gather(scale, "pod")
-                        g = jnp.sum(
-                            qs.astype(jnp.float32) * ss.reshape((-1,) + (1,) * g.ndim),
-                            axis=0,
-                        ).astype(g.dtype)
-                    else:
-                        g = jax.lax.psum(g, "pod")
+                    g = jax.lax.psum(g, "pod")
                 reduced[i] = g
-            if state.ef_residual is not None:
-                old_flat, ef_def = jax.tree_util.tree_flatten(state.ef_residual)
-                new_ef = jax.tree_util.tree_unflatten(
-                    ef_def,
-                    [new_ef_flat.get(i, old_flat[i]) for i in range(len(old_flat))],
-                )
-            else:
-                new_ef = None
 
-            grads_r = jax.tree_util.tree_unflatten(
-                treedef, reduced
-            )
             denom = jnp.maximum(n_yes, 1.0)
-            grads_r = jax.tree_util.tree_map(lambda g: g / denom.astype(g.dtype), grads_r)
+            reduced = [g / denom.astype(g.dtype) for g in reduced]
 
         # Global rollback condition: quorum AND post-reduction finiteness
         # (catches poisoned contributions inside the streamed reductions).
         with scope("train/clip"):
             all_finite = jnp.asarray(True)
-            for g in jax.tree_util.tree_leaves(grads_r):
+            for g in reduced:
                 all_finite = jnp.logical_and(all_finite, jnp.all(jnp.isfinite(g)))
             committed = jnp.logical_and(committed, all_finite)
+            # A poisoned replica's NaN reaches the streamed leaves through the
+            # reduction. The step then rolls back, but the update it discards
+            # must be finite: the gate's blend keeps a NaN (NaN * 0 is NaN).
+            reduced = [jnp.nan_to_num(g) if st else g
+                       for g, st in zip(reduced, streamed)]
 
             # Global grad norm for clipping (scalar psum over FSDP shards).
             local_sq = jnp.asarray(0.0, jnp.float32)
             repl_sq = jnp.asarray(0.0, jnp.float32)
-            flat_r = jax.tree_util.tree_flatten_with_path(grads_r)[0]
-            for (path, g), s in zip(flat_r, spec_flat):
+            for g, d in zip(reduced, fsdp):
                 gs = jnp.sum(jnp.square(g.astype(jnp.float32)))
-                if shd.fsdp_dim(s) is None:
+                if d is None:
                     repl_sq = repl_sq + gs
                 else:
                     local_sq = local_sq + gs
@@ -305,24 +232,19 @@ def build_train_step(
         # --- Sharded AdamW on local shards; quorum-gated apply.
         with scope("train/adamw"):
             new_params, new_opt = adamw.update(
-                opt_cfg, grads_r, state.opt, params, grad_norm=grad_norm
+                opt_cfg, jax.tree_util.tree_unflatten(treedef, reduced), state.opt,
+                params, grad_norm=grad_norm
             )
+
         c = committed.astype(jnp.float32)
-
-        def gate(new, old):
-            return jax.tree_util.tree_map(
-                lambda a, b: (a.astype(jnp.float32) * c
-                              + b.astype(jnp.float32) * (1 - c)).astype(a.dtype),
-                new, old,
-            )
-
         with scope("train/gate"):
-            params_out = gate(new_params, params)
+            params_out, m_out, v_out, master_out = collective.apply_if_committed(
+                c,
+                (new_params, new_opt.m, new_opt.v, new_opt.master),
+                (params, state.opt.m, state.opt.v, state.opt.master),
+            )
             opt_out = adamw.OptState(
-                m=gate(new_opt.m, state.opt.m),
-                v=gate(new_opt.v, state.opt.v),
-                master=gate(new_opt.master, state.opt.master)
-                if state.opt.master is not None else None,
+                m=m_out, v=v_out, master=master_out,
                 step=state.opt.step + committed.astype(jnp.int32),
             )
 
@@ -335,7 +257,7 @@ def build_train_step(
             **{k: jax.lax.psum(jnp.nan_to_num(v) * vote, dp_axes) / denom
                for k, v in metrics.items()},
         }
-        return TrainState(params_out, opt_out, new_ef), out_metrics
+        return TrainState(params_out, opt_out), out_metrics
 
     # ---- wrap: shard_map manual over DP, auto over model.
     manual = tuple(dp_axes)
@@ -362,7 +284,6 @@ def build_train_step(
         )
         return f(state, batch)
 
-    metrics_sharding = None
     state_shardings = shd.named(mesh, specs)
     jitted = jax.jit(
         wrapped,

@@ -40,7 +40,6 @@ class TrainerConfig:
     seq_len: int = 64
     seed: int = 0
     track: str = "fast"            # fast | classic (in-graph consensus)
-    compress_pod: bool = False
     ckpt_dir: Optional[str] = None
     ckpt_every: int = 0            # 0 = only final
     keep_last: int = 3
@@ -64,7 +63,6 @@ class Trainer:
         self.state: Optional[spmd.TrainState] = None  # after train()
         self.step_fn, self.state_shardings, self.batch_shard_fn = spmd.build_train_step(
             self.model, cfg.opt, self.mesh, track=cfg.track,
-            compress_pod=cfg.compress_pod,
         )
         self.ckpt = (
             CheckpointManager(
@@ -89,9 +87,7 @@ class Trainer:
     def init_state(self) -> spmd.TrainState:
         with self.mesh:
             state = jax.jit(
-                lambda rng: spmd.make_train_state(
-                    self.model, self.cfg.opt, rng, self.cfg.compress_pod
-                ),
+                lambda rng: spmd.make_train_state(self.model, self.cfg.opt, rng),
                 out_shardings=self.state_shardings,
             )(jax.random.PRNGKey(self.cfg.seed))
         return state
@@ -99,9 +95,7 @@ class Trainer:
     def restore_or_init(self) -> (int, spmd.TrainState):
         if self.ckpt is not None and self.ckpt.latest_step() is not None:
             tpl = jax.eval_shape(
-                lambda rng: spmd.make_train_state(
-                    self.model, self.cfg.opt, rng, self.cfg.compress_pod
-                ),
+                lambda rng: spmd.make_train_state(self.model, self.cfg.opt, rng),
                 jax.random.PRNGKey(0),
             )
             step, trees = self.ckpt.restore(

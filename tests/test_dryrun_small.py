@@ -30,9 +30,9 @@ for arch in ("qwen3-1.7b", "jamba-v0.1-52b", "xlstm-1.3b"):
     model = zoo.build(cfg, dtype=jnp.bfloat16)
     opt = AdamWConfig()
     step_fn, _, _ = spmd.build_train_step(model, opt, mesh)
-    tpl = jax.eval_shape(lambda r: spmd.make_train_state(model, opt, r, False),
+    tpl = jax.eval_shape(lambda r: spmd.make_train_state(model, opt, r),
                          jax.random.PRNGKey(0))
-    specs = spmd.state_specs(model, opt, mesh, False)
+    specs = spmd.state_specs(model, opt, mesh)
     structs = _shaped(tpl, mesh, specs)
     B, T = 8, 32
     batch = {
